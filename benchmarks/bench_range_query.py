@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.compat import use_compile_cache
 from repro.data import spatial_gen
 from repro.query import range as range_mod
 from repro.serve import PlacementPolicy, ServeConfig, SpatialServer
@@ -398,4 +399,5 @@ def main(smoke: bool = False, json_out: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache(str(pathlib.Path(__file__).resolve().parents[1]))
     main(smoke="--smoke" in sys.argv, json_out="--json" in sys.argv)

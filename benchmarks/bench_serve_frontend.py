@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.compat import use_compile_cache
 from repro.data import spatial_gen
 from repro.serve import ServeConfig, SpatialServer
 from repro.serve.frontend import (FrontendConfig, poisson_workload,
@@ -153,4 +154,5 @@ def main(smoke: bool = False, json_out: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache(str(pathlib.Path(__file__).resolve().parents[1]))
     main(smoke="--smoke" in sys.argv, json_out="--json" in sys.argv)

@@ -4,8 +4,11 @@ Prints ``name,us_per_call,derived`` CSV lines (stdout), one per cell.
 """
 from __future__ import annotations
 
+import os
 import sys
 import traceback
+
+from repro.core.compat import use_compile_cache
 
 from . import (bench_balanced_batch, bench_cost_model, bench_join,
                bench_kernels, bench_paper_hillclimb,
@@ -27,6 +30,7 @@ ALL = {
 
 
 def main() -> None:
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     which = sys.argv[1:] or list(ALL)
     print("name,us_per_call,derived")
     failed = []

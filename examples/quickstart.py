@@ -6,11 +6,14 @@ spatial join whose result is checked against the brute-force oracle.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import os
+
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import metrics
+from repro.core.compat import use_compile_cache
 from repro.core.partition import api, partition_counts
 from repro.data import spatial_gen
 from repro.kernels.mbr_join import ref as oracle
@@ -18,6 +21,7 @@ from repro.query import engine
 
 N, PAYLOAD = 4000, 250
 
+use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 key = jax.random.PRNGKey(0)
 r = spatial_gen.dataset("osm", key, N)
 s = spatial_gen.dataset("osm", jax.random.PRNGKey(1), N // 2)

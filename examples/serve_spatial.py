@@ -28,12 +28,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro.core.compat import use_compile_cache
 from repro.data import spatial_gen
 from repro.serve import ServeConfig, SpatialServer
 
 N, Q, K = 20_000, 1024, 10
 
 if __name__ == "__main__":
+    use_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     mbrs = spatial_gen.dataset("osm", jax.random.PRNGKey(0), N)
     mesh = Mesh(np.array(jax.devices()), ("d",))
     n_dev = len(mesh.devices.ravel())

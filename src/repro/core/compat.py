@@ -1,28 +1,22 @@
-"""Version compatibility shims for jax API drift.
+"""JAX entry-point helpers shared across the repository.
 
-The repo targets the container's pinned jax; newer/older releases moved
-``shard_map`` (``jax.experimental.shard_map`` → ``jax.shard_map``) and
-renamed its replication-check kwarg (``check_rep`` → ``check_vma``).
-Everything in-repo imports ``shard_map`` from here so call sites can use
-the modern spelling regardless of the installed version.
+``shard_map`` and ``all_to_all`` are the single spelling every call
+site (and reprolint) uses for the SPMD primitives, so a future API move
+lands in one place.  ``use_compile_cache`` is for entry points only
+(scripts, examples, benchmarks) — the library never configures a cache
+on import.
 """
 from __future__ import annotations
 
-import jax
+import os
 
-try:  # jax >= 0.6: top-level export, kwarg is check_vma
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x: experimental module, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
+import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the modern signature on any supported jax."""
-    kw = {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map`` with keyword-only specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def all_to_all(x, axis_name: str):
@@ -30,10 +24,25 @@ def all_to_all(x, axis_name: str):
     ``j`` is what device ``j`` held in *its* row for this device.
 
     The one exchange shape the serving stack uses (leading axis =
-    mesh-axis size, ``split_axis=concat_axis=0``), wrapped here next to
-    ``shard_map`` so collective call sites survive jax API drift in one
-    place.  ``tiled=True`` keeps the leading axis in place (row ``j``
-    of the result came from device ``j``).
+    mesh-axis size, ``split_axis=concat_axis=0``).  ``tiled=True``
+    keeps the leading axis in place (row ``j`` of the result came from
+    device ``j``).
     """
     return jax.lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0,
                               tiled=True)
+
+
+def use_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself; nothing else is configured).  Otherwise the cache lives at
+    the fixed path ``<root>/.jax_cache`` — fixed, because the path is
+    part of what makes a later run find the entries.  -> the directory.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.abspath(root), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

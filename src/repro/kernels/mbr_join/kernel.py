@@ -6,8 +6,10 @@ of boolean compares from rank-1 broadcasts.  Layout: coordinates arrive
 as (4, N) — component-major — so the object axis is the 128-lane axis.
 
 Two entry points:
-- ``count``: grid cell (i, j) reduces its (BR, BS) block to one int32 —
-  O(Nb×Mb) output, used for selectivity/λ statistics and join counting.
+- ``count``: grid cell (j, i) reduces its (BR, BS) block over the r
+  axis and accumulates the (1, BS) per-s partial counts across the r
+  blocks in its resident output block — O(M) output, used for
+  selectivity/λ statistics and join counting.
 - ``mask``:  writes the full boolean block — used for pair extraction on
   moderate tile sizes.
 
@@ -38,8 +40,12 @@ def _block_hits(r_ref, s_ref):
 
 
 def _count_kernel(r_ref, s_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
     hits = _block_hits(r_ref, s_ref)
-    out_ref[0, 0] = jnp.sum(hits.astype(jnp.int32))
+    out_ref[...] += jnp.sum(hits.astype(jnp.int32), axis=0, keepdims=True)
 
 
 def _mask_kernel(r_ref, s_ref, out_ref):
@@ -48,18 +54,18 @@ def _mask_kernel(r_ref, s_ref, out_ref):
 
 def count_pallas(r4: jax.Array, s4: jax.Array, br: int = DEFAULT_BR,
                  bs: int = DEFAULT_BS, interpret: bool = False) -> jax.Array:
-    """r4: (4, N), s4: (4, M), N % br == 0, M % bs == 0 -> (N/br, M/bs) int32."""
+    """r4: (4, N), s4: (4, M), N % br == 0, M % bs == 0 -> (1, M) int32
+    per-s hit counts (the join count is their sum)."""
     n, m = r4.shape[1], s4.shape[1]
-    grid = (n // br, m // bs)
     return pl.pallas_call(
         _count_kernel,
-        grid=grid,
+        grid=(m // bs, n // br),
         in_specs=[
-            pl.BlockSpec((4, br), lambda i, j: (0, i)),
-            pl.BlockSpec((4, bs), lambda i, j: (0, j)),
+            pl.BlockSpec((4, br), lambda j, i: (0, i)),
+            pl.BlockSpec((4, bs), lambda j, i: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.int32),
+        out_specs=pl.BlockSpec((1, bs), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
         interpret=interpret,
     )(r4, s4)
 

@@ -1,7 +1,8 @@
 """Public jit'd wrappers for the mbr_join kernel.
 
 Handles padding to block multiples (with never-intersecting sentinel
-boxes), component-major layout, and CPU fallback to interpret mode.
+boxes), component-major layout, and interpret mode off the TPU
+(the CPU test path).
 """
 from __future__ import annotations
 

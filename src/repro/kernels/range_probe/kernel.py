@@ -1,57 +1,50 @@
-"""Blocked range-probe kernel (TPU Pallas): query boxes vs tiled layout.
+"""Blocked range-probe kernel (TPU Pallas): query boxes vs member tiles.
 
 The serving hot spot: a (Q, 4) batch of range-query boxes is tested
-against a (T, cap, 4) partitioned layout (T tiles of cap member slots,
-the staging format of ``serve.engine``).  Like ``mbr_join`` this is a
-VPU problem — a (BQ, cap) block of boolean closed-box compares from
-rank-1 broadcasts; the member axis is the 128-lane axis.
+against member tiles of the ``serve.engine`` staging format.  Like
+``mbr_join`` this is a VPU problem — (BQ, 128) blocks of boolean
+closed-box compares from rank-1 broadcasts, queries on the sublane axis
+and members on the 128-lane axis.
 
-Layout: queries arrive component-major (4, Q); tiles arrive per-tile
-component-major (T, 4, cap) so grid cell (t, i) streams one tile's
-coordinate block and one query block through VMEM.
+One kernel serves every probe family; the callers in ``ops`` differ
+only in how many member rows they stream:
 
-Four entry points:
-- ``count``: grid cell (t, i) reduces its (BQ, cap) hit block over the
-  member axis — per-(tile, query) hit counts, O(T×Q) output.  This is
-  the dense throughput path (count/selectivity queries, kNN deepening).
-- ``mask``: writes the full (BQ, cap) boolean block — used for hit-id
-  extraction on moderate tile counts.
-- ``gather_count`` / ``gather_mask``: the **routed** variants.  The
-  caller has already gathered each query's candidate tiles (router
-  output) into a per-query ``(Q, F, 4, cap)`` stack, so grid cell
-  (f, i) streams a (BQ, 1, 4, cap) slab where query row j carries *its
-  own* f-th candidate tile.  Work drops from O(Q·T·cap) to
-  O(Q·F·cap) — the partition-pruning win the paper's fan-out metric
-  predicts, realised as compute instead of a report.
-- ``*_skip``: the **local-index** variants (LocationSpark's second,
-  intra-partition index layer).  Staging sorts each tile's members
-  along x and summarises every ``CHUNK``-lane (128-member) slot group
-  with one MBR ("chunk box"); the kernels test the query block against
-  a tile's C chunk boxes first and only run the full (BQ, CHUNK)
-  member compare for chunks some query in the block can hit
-  (``pl.when``) — dead chunks cost C scalar compares instead of
-  CHUNK·4 member compares.  Per-query predication (``hits & live``)
-  keeps the output bit-identical to the unindexed kernels whenever the
-  chunk boxes bound their members, and identical to the ``ref``
-  chunk-masked oracles unconditionally.
+- **dense** (``R == 1``): every query sees the same ``n_cols == T``
+  tiles — the all-tile oracle sweep.
+- **gathered** (``R == Q``): row j of the member stack holds query j's
+  *own* ``n_cols == F`` candidate tiles (router output), so work drops
+  from O(Q·T·cap) to O(Q·F·cap) — the partition-pruning win the
+  paper's fan-out metric predicts.
+
+Grid: ``(query block i, tile column t, member block k)``.  Member block
+k covers ``G`` consecutive 128-member chunks (``G`` divides the chunk
+count, at most ``MAX_CHUNKS_PER_BLOCK``), so fast memory per grid cell
+is bounded by ``BQ·G·128`` members however large ``cap`` grows.  The
+count form accumulates per-lane partial counts across k in its
+resident (BQ, 128) output block; the wrapper sums the lanes.  The mask
+form writes its (BQ, G·128) hit block.
+
+Local index (``cboxes``, LocationSpark's intra-partition layer): each
+128-member chunk carries one MBR ("chunk box").  A member hit counts
+only if its query also hits the chunk box; chunks no query of the block
+hits skip the member compare entirely (``pl.when``).  The per-query
+test is a (BQ, 1) column select that turns a missing query's ``xmin``
+into NaN, which compares false against everything — answers equal the
+``ref`` chunk-masked oracles bit for bit, whether or not the chunk
+boxes bound their members.
+
+Tombstones (keyword-only ``alive``, the ingest engine's per-slot mask):
+a dead slot's member ``xmin`` reads as NaN, so it never hits; under
+``cboxes`` a chunk with no live slot is skipped too.
 
 Padding contract (same as mbr_join): callers pad query slots, member
 slots, and absent candidate tiles with *inverted* sentinel boxes
-(xmin > xmax), which intersect nothing, so no validity mask is
-streamed through VMEM.  All-sentinel chunks get inverted chunk boxes
-and are always skipped.
-
-Every entry point takes an optional **alive mask** (keyword-only
-``alive``; dense: (T, cap) bool, gathered: (Q, F, cap) bool) — the
-tombstone-delete layer of the ingest engine (``serve.layout``).  A hit
-counts only if its member slot is alive; the ``*_skip`` variants
-additionally ``pl.when`` a whole chunk away when none of its slots is
-alive, so a tombstone-riddled chunk costs one scalar reduce even when
-its (stale, superset) chunk box still overlaps the query.
-``alive=None`` compiles the original mask-free kernels — the all-live
-fast path, bit-identical to an all-``True`` mask.
+(xmin > xmax), which intersect nothing; all-sentinel chunks carry
+inverted chunk boxes and are always skipped.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -59,457 +52,131 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BQ = 128
 CHUNK = 128  # members summarised per chunk box (the VPU lane width)
+MAX_CHUNKS_PER_BLOCK = 8
 
 
-def _block_hits(q_ref, t_ref):
-    qx0 = q_ref[0, :][:, None]   # (BQ, 1)
-    qy0 = q_ref[1, :][:, None]
-    qx1 = q_ref[2, :][:, None]
-    qy1 = q_ref[3, :][:, None]
-    sx0 = t_ref[0, 0, :][None, :]   # (1, cap)
-    sy0 = t_ref[0, 1, :][None, :]
-    sx1 = t_ref[0, 2, :][None, :]
-    sy1 = t_ref[0, 3, :][None, :]
-    return (qx0 <= sx1) & (sx0 <= qx1) & (qy0 <= sy1) & (sy0 <= qy1)
+def chunks_per_block(n_chunks: int) -> int:
+    """Largest divisor of ``n_chunks`` that is at most
+    ``MAX_CHUNKS_PER_BLOCK`` — the member block is G whole chunks."""
+    return max(g for g in range(1, MAX_CHUNKS_PER_BLOCK + 1)
+               if n_chunks % g == 0)
 
 
-def _count_kernel(q_ref, t_ref, out_ref):
-    hits = _block_hits(q_ref, t_ref)
-    out_ref[0, :] = jnp.sum(hits.astype(jnp.int32), axis=1)
+def _probe_kernel(*refs, g: int, skip: bool, masked: bool, mask: bool):
+    q_ref, t_ref, *rest = refs
+    cb_ref = rest.pop(0) if skip else None
+    a_ref = rest.pop(0) if masked else None
+    (out_ref,) = rest
+
+    if not mask:
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    qx0, qy0 = q_ref[:, 0:1], q_ref[:, 1:2]        # (BQ, 1)
+    qx1, qy1 = q_ref[:, 2:3], q_ref[:, 3:4]
+    for j in range(g):
+        sl = slice(j * CHUNK, (j + 1) * CHUNK)
+        go = None
+        qx0_j = qx0
+        if skip:
+            c = 4 * j
+            cx0, cy0 = cb_ref[0, :, c:c + 1], cb_ref[0, :, c + 1:c + 2]
+            cx1, cy1 = cb_ref[0, :, c + 2:c + 3], cb_ref[0, :, c + 3:c + 4]
+            live = (qx0 <= cx1) & (cx0 <= qx1) & (qy0 <= cy1) & (cy0 <= qy1)
+            qx0_j = jnp.where(live, qx0, jnp.nan)   # missed chunk: no hit
+            go = jnp.max(live.astype(jnp.float32)) > 0.0
+        sx0 = t_ref[0, :, sl]                      # (1 | BQ, CHUNK)
+        if masked:
+            slot_live = a_ref[:, sl]
+            sx0 = jnp.where(slot_live, sx0, jnp.nan)   # dead slot: no hit
+            if skip:
+                go = go & (jnp.max(slot_live.astype(jnp.float32)) > 0.0)
+
+        def hits(qx0_j=qx0_j, sx0=sx0, sl=sl):
+            return ((qx0_j <= t_ref[2, :, sl]) & (sx0 <= qx1)
+                    & (qy0 <= t_ref[3, :, sl]) & (t_ref[1, :, sl] <= qy1))
+
+        if mask:
+            if go is None:
+                out_ref[:, sl] = hits()
+            else:
+                @pl.when(go)
+                def _(hits=hits, sl=sl):
+                    out_ref[:, sl] = hits()
+
+                @pl.when(jnp.logical_not(go))
+                def _(sl=sl):
+                    out_ref[:, sl] = jnp.zeros((out_ref.shape[0], CHUNK),
+                                               out_ref.dtype)
+        elif go is None:
+            out_ref[...] += hits().astype(jnp.int32)
+        else:
+            @pl.when(go)
+            def _(hits=hits):
+                out_ref[...] += hits().astype(jnp.int32)
 
 
-def _mask_kernel(q_ref, t_ref, out_ref):
-    out_ref[0, ...] = _block_hits(q_ref, t_ref)
+def probe_pallas(qboxes: jax.Array, tiles: jax.Array,
+                 cboxes: jax.Array | None = None, *,
+                 alive: jax.Array | None = None, mask: bool = False,
+                 bq: int = DEFAULT_BQ, interpret: bool = False) -> jax.Array:
+    """Closed-box hits of query boxes against member tiles.
 
+    qboxes: (Q, 4) f32, ``Q % bq == 0``.  tiles: (4, R, n_cols, cap)
+    f32 component-major member boxes, ``cap % CHUNK == 0``; ``R == 1``
+    shares the tiles with every query (dense), ``R == Q`` gives query j
+    its own row (gathered).  cboxes: (R, n_cols, cap // CHUNK, 4) chunk
+    boxes or None (no local index).  alive: (R, n_cols, cap) bool or
+    None (all live).
 
-def _count_alive_kernel(q_ref, t_ref, a_ref, out_ref):
-    hits = _block_hits(q_ref, t_ref) & a_ref[0, :][None, :]
-    out_ref[0, :] = jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _mask_alive_kernel(q_ref, t_ref, a_ref, out_ref):
-    out_ref[0, ...] = _block_hits(q_ref, t_ref) & a_ref[0, :][None, :]
-
-
-def _dense_specs(bq: int, cap: int, alive) -> list:
-    """Input specs shared by the dense kernels: query block, one tile's
-    component block, and (when masking) that tile's alive row."""
-    specs = [
-        pl.BlockSpec((4, bq), lambda ti, i: (0, i)),
-        pl.BlockSpec((1, 4, cap), lambda ti, i: (ti, 0, 0)),
-    ]
-    if alive is not None:
-        specs.append(pl.BlockSpec((1, cap), lambda ti, i: (ti, 0)))
-    return specs
-
-
-def count_pallas(q4: jax.Array, tiles: jax.Array, bq: int = DEFAULT_BQ,
-                 interpret: bool = False, *,
-                 alive: jax.Array | None = None) -> jax.Array:
-    """q4: (4, Q), tiles: (T, 4, cap); Q % bq == 0, cap % 128 == 0
-    -> (T, Q) int32 per-(tile, query) hit counts.  ``alive``: (T, cap)
-    bool — dead member slots never count."""
-    q = q4.shape[1]
-    t, _, cap = tiles.shape
-    grid = (t, q // bq)
-    args = (q4, tiles) if alive is None else (q4, tiles, alive)
-    return pl.pallas_call(
-        _count_kernel if alive is None else _count_alive_kernel,
-        grid=grid,
-        in_specs=_dense_specs(bq, cap, alive),
-        out_specs=pl.BlockSpec((1, bq), lambda ti, i: (ti, i)),
-        out_shape=jax.ShapeDtypeStruct((t, q), jnp.int32),
-        interpret=interpret,
-    )(*args)
-
-
-def mask_pallas(q4: jax.Array, tiles: jax.Array, bq: int = DEFAULT_BQ,
-                interpret: bool = False, *,
-                alive: jax.Array | None = None) -> jax.Array:
-    """q4: (4, Q), tiles: (T, 4, cap) -> (T, Q, cap) bool hit table
-    (dead slots read False under ``alive``)."""
-    q = q4.shape[1]
-    t, _, cap = tiles.shape
-    grid = (t, q // bq)
-    args = (q4, tiles) if alive is None else (q4, tiles, alive)
-    return pl.pallas_call(
-        _mask_kernel if alive is None else _mask_alive_kernel,
-        grid=grid,
-        in_specs=_dense_specs(bq, cap, alive),
-        out_specs=pl.BlockSpec((1, bq, cap), lambda ti, i: (ti, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, q, cap), jnp.bool_),
-        interpret=interpret,
-    )(*args)
-
-
-def _gather_block_hits(q_ref, g_ref):
-    # query row j of the block is compared against its OWN gathered tile:
-    # g_ref block is (BQ, 1, 4, cap), so every coordinate slab below is
-    # (BQ, cap) with per-row tile data — still rank-1-broadcast VPU work.
-    qx0 = q_ref[0, :][:, None]   # (BQ, 1)
-    qy0 = q_ref[1, :][:, None]
-    qx1 = q_ref[2, :][:, None]
-    qy1 = q_ref[3, :][:, None]
-    sx0 = g_ref[:, 0, 0, :]      # (BQ, cap)
-    sy0 = g_ref[:, 0, 1, :]
-    sx1 = g_ref[:, 0, 2, :]
-    sy1 = g_ref[:, 0, 3, :]
-    return (qx0 <= sx1) & (sx0 <= qx1) & (qy0 <= sy1) & (sy0 <= qy1)
-
-
-def _gather_count_kernel(q_ref, g_ref, out_ref):
-    hits = _gather_block_hits(q_ref, g_ref)
-    out_ref[:, 0] = jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _gather_mask_kernel(q_ref, g_ref, out_ref):
-    out_ref[:, 0, :] = _gather_block_hits(q_ref, g_ref)
-
-
-def _gather_count_alive_kernel(q_ref, g_ref, ga_ref, out_ref):
-    hits = _gather_block_hits(q_ref, g_ref) & ga_ref[:, 0, :]
-    out_ref[:, 0] = jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _gather_mask_alive_kernel(q_ref, g_ref, ga_ref, out_ref):
-    out_ref[:, 0, :] = _gather_block_hits(q_ref, g_ref) & ga_ref[:, 0, :]
-
-
-def _gather_specs(bq: int, cap: int, alive) -> list:
-    """Input specs shared by the gathered kernels: query block, per-row
-    candidate-f slab, and (when masking) the matching alive slab."""
-    specs = [
-        pl.BlockSpec((4, bq), lambda fi, i: (0, i)),
-        pl.BlockSpec((bq, 1, 4, cap), lambda fi, i: (i, fi, 0, 0)),
-    ]
-    if alive is not None:
-        specs.append(pl.BlockSpec((bq, 1, cap), lambda fi, i: (i, fi, 0)))
-    return specs
-
-
-def gather_count_pallas(q4: jax.Array, gtiles: jax.Array,
-                        bq: int = DEFAULT_BQ,
-                        interpret: bool = False, *,
-                        alive: jax.Array | None = None) -> jax.Array:
-    """Routed probe, count form.
-
-    q4: (4, Q) component-major queries; gtiles: (Q, F, 4, cap) each
-    query's gathered candidate tiles (absent candidates = sentinel
-    tiles).  Q % bq == 0, cap % 128 == 0 -> (Q, F) int32 per-(query,
-    candidate) hit counts.  ``alive``: (Q, F, cap) gathered alive mask.
+    -> ``mask=False``: (Q, n_cols) int32 hit counts;
+       ``mask=True``: (Q, n_cols, cap) bool hit table.
     """
-    q = q4.shape[1]
-    _, f, _, cap = gtiles.shape
-    grid = (f, q // bq)
-    args = (q4, gtiles) if alive is None else (q4, gtiles, alive)
-    return pl.pallas_call(
-        _gather_count_kernel if alive is None else _gather_count_alive_kernel,
-        grid=grid,
-        in_specs=_gather_specs(bq, cap, alive),
-        out_specs=pl.BlockSpec((bq, 1), lambda fi, i: (i, fi)),
-        out_shape=jax.ShapeDtypeStruct((q, f), jnp.int32),
-        interpret=interpret,
-    )(*args)
-
-
-def gather_mask_pallas(q4: jax.Array, gtiles: jax.Array,
-                       bq: int = DEFAULT_BQ,
-                       interpret: bool = False, *,
-                       alive: jax.Array | None = None) -> jax.Array:
-    """Routed probe, mask form: (4, Q) x (Q, F, 4, cap) -> (Q, F, cap)
-    bool hit table (hit-id extraction over candidate tiles only)."""
-    q = q4.shape[1]
-    _, f, _, cap = gtiles.shape
-    grid = (f, q // bq)
-    args = (q4, gtiles) if alive is None else (q4, gtiles, alive)
-    return pl.pallas_call(
-        _gather_mask_kernel if alive is None else _gather_mask_alive_kernel,
-        grid=grid,
-        in_specs=_gather_specs(bq, cap, alive),
-        out_specs=pl.BlockSpec((bq, 1, cap), lambda fi, i: (i, fi, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, f, cap), jnp.bool_),
-        interpret=interpret,
-    )(*args)
-
-
-# --------------------------------------------------------------------------
-# chunk-skipping (local-index) variants
-# --------------------------------------------------------------------------
-
-def _chunk_live_dense(q_ref, cb_ref, c: int):
-    """(BQ,) bool: which queries of the block hit chunk ``c``'s box.
-    cb_ref: (1, C, 4) this tile's chunk boxes."""
-    x0, y0 = cb_ref[0, c, 0], cb_ref[0, c, 1]
-    x1, y1 = cb_ref[0, c, 2], cb_ref[0, c, 3]
-    return ((q_ref[0, :] <= x1) & (x0 <= q_ref[2, :])
-            & (q_ref[1, :] <= y1) & (y0 <= q_ref[3, :]))
-
-
-def _block_hits_chunk(q_ref, t_ref, c: int):
-    """(BQ, CHUNK) member compare restricted to chunk ``c``."""
-    sl = slice(c * CHUNK, (c + 1) * CHUNK)
-    qx0 = q_ref[0, :][:, None]
-    qy0 = q_ref[1, :][:, None]
-    qx1 = q_ref[2, :][:, None]
-    qy1 = q_ref[3, :][:, None]
-    sx0 = t_ref[0, 0, sl][None, :]
-    sy0 = t_ref[0, 1, sl][None, :]
-    sx1 = t_ref[0, 2, sl][None, :]
-    sy1 = t_ref[0, 3, sl][None, :]
-    return (qx0 <= sx1) & (sx0 <= qx1) & (qy0 <= sy1) & (sy0 <= qy1)
-
-
-def _count_skip_kernel(q_ref, t_ref, cb_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = t_ref.shape[2] // CHUNK
-    out_ref[0, :] = jnp.zeros((bq,), jnp.int32)
-    for c in range(n_chunks):
-        live = _chunk_live_dense(q_ref, cb_ref, c)
-
-        @pl.when(jnp.any(live))
-        def _(c=c, live=live):
-            hits = _block_hits_chunk(q_ref, t_ref, c) & live[:, None]
-            out_ref[0, :] += jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _mask_skip_kernel(q_ref, t_ref, cb_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = t_ref.shape[2] // CHUNK
-    out_ref[0, ...] = jnp.zeros((bq, t_ref.shape[2]), jnp.bool_)
-    for c in range(n_chunks):
-        live = _chunk_live_dense(q_ref, cb_ref, c)
-
-        @pl.when(jnp.any(live))
-        def _(c=c, live=live):
-            out_ref[0, :, c * CHUNK:(c + 1) * CHUNK] = (
-                _block_hits_chunk(q_ref, t_ref, c) & live[:, None])
-
-
-def _count_skip_alive_kernel(q_ref, t_ref, cb_ref, a_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = t_ref.shape[2] // CHUNK
-    out_ref[0, :] = jnp.zeros((bq,), jnp.int32)
-    for c in range(n_chunks):
-        live = _chunk_live_dense(q_ref, cb_ref, c)
-        alive_c = a_ref[0, c * CHUNK:(c + 1) * CHUNK]
-
-        @pl.when(jnp.any(live) & jnp.any(alive_c))
-        def _(c=c, live=live, alive_c=alive_c):
-            hits = (_block_hits_chunk(q_ref, t_ref, c)
-                    & live[:, None] & alive_c[None, :])
-            out_ref[0, :] += jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _mask_skip_alive_kernel(q_ref, t_ref, cb_ref, a_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = t_ref.shape[2] // CHUNK
-    out_ref[0, ...] = jnp.zeros((bq, t_ref.shape[2]), jnp.bool_)
-    for c in range(n_chunks):
-        live = _chunk_live_dense(q_ref, cb_ref, c)
-        alive_c = a_ref[0, c * CHUNK:(c + 1) * CHUNK]
-
-        @pl.when(jnp.any(live) & jnp.any(alive_c))
-        def _(c=c, live=live, alive_c=alive_c):
-            out_ref[0, :, c * CHUNK:(c + 1) * CHUNK] = (
-                _block_hits_chunk(q_ref, t_ref, c)
-                & live[:, None] & alive_c[None, :])
-
-
-def _dense_skip_specs(bq: int, cap: int, c: int, alive) -> list:
-    specs = [
-        pl.BlockSpec((4, bq), lambda ti, i: (0, i)),
-        pl.BlockSpec((1, 4, cap), lambda ti, i: (ti, 0, 0)),
-        pl.BlockSpec((1, c, 4), lambda ti, i: (ti, 0, 0)),
-    ]
-    if alive is not None:
-        specs.append(pl.BlockSpec((1, cap), lambda ti, i: (ti, 0)))
-    return specs
-
-
-def count_skip_pallas(q4: jax.Array, tiles: jax.Array, cboxes: jax.Array,
-                      bq: int = DEFAULT_BQ,
-                      interpret: bool = False, *,
-                      alive: jax.Array | None = None) -> jax.Array:
-    """Dense probe with chunk skipping.
-
-    q4: (4, Q), tiles: (T, 4, cap), cboxes: (T, C, 4) per-chunk MBRs
-    (C == cap // CHUNK); Q % bq == 0, cap % CHUNK == 0 -> (T, Q) int32.
-    ``alive``: (T, cap) bool — all-dead chunks are skipped entirely.
-    """
-    q = q4.shape[1]
-    t, _, cap = tiles.shape
-    grid = (t, q // bq)
-    c = cboxes.shape[1]
-    args = (q4, tiles, cboxes) if alive is None else (q4, tiles, cboxes, alive)
-    return pl.pallas_call(
-        _count_skip_kernel if alive is None else _count_skip_alive_kernel,
-        grid=grid,
-        in_specs=_dense_skip_specs(bq, cap, c, alive),
-        out_specs=pl.BlockSpec((1, bq), lambda ti, i: (ti, i)),
-        out_shape=jax.ShapeDtypeStruct((t, q), jnp.int32),
-        interpret=interpret,
-    )(*args)
-
-
-def mask_skip_pallas(q4: jax.Array, tiles: jax.Array, cboxes: jax.Array,
-                     bq: int = DEFAULT_BQ,
-                     interpret: bool = False, *,
-                     alive: jax.Array | None = None) -> jax.Array:
-    """Dense mask with chunk skipping: -> (T, Q, cap) bool (skipped
-    chunks and dead slots read False)."""
-    q = q4.shape[1]
-    t, _, cap = tiles.shape
-    grid = (t, q // bq)
-    c = cboxes.shape[1]
-    args = (q4, tiles, cboxes) if alive is None else (q4, tiles, cboxes, alive)
-    return pl.pallas_call(
-        _mask_skip_kernel if alive is None else _mask_skip_alive_kernel,
-        grid=grid,
-        in_specs=_dense_skip_specs(bq, cap, c, alive),
-        out_specs=pl.BlockSpec((1, bq, cap), lambda ti, i: (ti, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((t, q, cap), jnp.bool_),
-        interpret=interpret,
-    )(*args)
-
-
-def _chunk_live_gather(q_ref, gcb_ref, c: int):
-    """(BQ,) bool: row j's query vs row j's OWN candidate's chunk-c box.
-    gcb_ref: (BQ, 1, C, 4) gathered chunk boxes."""
-    x0, y0 = gcb_ref[:, 0, c, 0], gcb_ref[:, 0, c, 1]
-    x1, y1 = gcb_ref[:, 0, c, 2], gcb_ref[:, 0, c, 3]
-    return ((q_ref[0, :] <= x1) & (x0 <= q_ref[2, :])
-            & (q_ref[1, :] <= y1) & (y0 <= q_ref[3, :]))
-
-
-def _gather_block_hits_chunk(q_ref, g_ref, c: int):
-    """(BQ, CHUNK) per-row member compare restricted to chunk ``c``."""
-    sl = slice(c * CHUNK, (c + 1) * CHUNK)
-    qx0 = q_ref[0, :][:, None]
-    qy0 = q_ref[1, :][:, None]
-    qx1 = q_ref[2, :][:, None]
-    qy1 = q_ref[3, :][:, None]
-    sx0 = g_ref[:, 0, 0, sl]
-    sy0 = g_ref[:, 0, 1, sl]
-    sx1 = g_ref[:, 0, 2, sl]
-    sy1 = g_ref[:, 0, 3, sl]
-    return (qx0 <= sx1) & (sx0 <= qx1) & (qy0 <= sy1) & (sy0 <= qy1)
-
-
-def _gather_count_skip_kernel(q_ref, g_ref, gcb_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = g_ref.shape[3] // CHUNK
-    out_ref[:, 0] = jnp.zeros((bq,), jnp.int32)
-    for c in range(n_chunks):
-        live = _chunk_live_gather(q_ref, gcb_ref, c)
-
-        @pl.when(jnp.any(live))
-        def _(c=c, live=live):
-            hits = _gather_block_hits_chunk(q_ref, g_ref, c) & live[:, None]
-            out_ref[:, 0] += jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _gather_mask_skip_kernel(q_ref, g_ref, gcb_ref, out_ref):
-    bq = q_ref.shape[1]
-    cap = g_ref.shape[3]
+    nq = qboxes.shape[0]
+    _, r, n_cols, cap = tiles.shape
     n_chunks = cap // CHUNK
-    out_ref[:, 0, :] = jnp.zeros((bq, cap), jnp.bool_)
-    for c in range(n_chunks):
-        live = _chunk_live_gather(q_ref, gcb_ref, c)
+    g = chunks_per_block(n_chunks)
+    nk = n_chunks // g
+    blk = g * CHUNK
+    gathered = r > 1
+    skip, masked = cboxes is not None, alive is not None
 
-        @pl.when(jnp.any(live))
-        def _(c=c, live=live):
-            out_ref[:, 0, c * CHUNK:(c + 1) * CHUNK] = (
-                _gather_block_hits_chunk(q_ref, g_ref, c) & live[:, None])
+    def rows(i):
+        return i if gathered else 0
 
-
-def _gather_count_skip_alive_kernel(q_ref, g_ref, gcb_ref, ga_ref, out_ref):
-    bq = q_ref.shape[1]
-    n_chunks = g_ref.shape[3] // CHUNK
-    out_ref[:, 0] = jnp.zeros((bq,), jnp.int32)
-    for c in range(n_chunks):
-        live = _chunk_live_gather(q_ref, gcb_ref, c)
-        alive_c = ga_ref[:, 0, c * CHUNK:(c + 1) * CHUNK]
-
-        @pl.when(jnp.any(live) & jnp.any(alive_c))
-        def _(c=c, live=live, alive_c=alive_c):
-            hits = (_gather_block_hits_chunk(q_ref, g_ref, c)
-                    & live[:, None] & alive_c)
-            out_ref[:, 0] += jnp.sum(hits.astype(jnp.int32), axis=1)
-
-
-def _gather_mask_skip_alive_kernel(q_ref, g_ref, gcb_ref, ga_ref, out_ref):
-    bq = q_ref.shape[1]
-    cap = g_ref.shape[3]
-    n_chunks = cap // CHUNK
-    out_ref[:, 0, :] = jnp.zeros((bq, cap), jnp.bool_)
-    for c in range(n_chunks):
-        live = _chunk_live_gather(q_ref, gcb_ref, c)
-        alive_c = ga_ref[:, 0, c * CHUNK:(c + 1) * CHUNK]
-
-        @pl.when(jnp.any(live) & jnp.any(alive_c))
-        def _(c=c, live=live, alive_c=alive_c):
-            out_ref[:, 0, c * CHUNK:(c + 1) * CHUNK] = (
-                _gather_block_hits_chunk(q_ref, g_ref, c)
-                & live[:, None] & alive_c)
-
-
-def _gather_skip_specs(bq: int, cap: int, c: int, alive) -> list:
-    specs = [
-        pl.BlockSpec((4, bq), lambda fi, i: (0, i)),
-        pl.BlockSpec((bq, 1, 4, cap), lambda fi, i: (i, fi, 0, 0)),
-        pl.BlockSpec((bq, 1, c, 4), lambda fi, i: (i, fi, 0, 0)),
+    args = [qboxes, tiles.reshape(4, r, n_cols * cap)]
+    in_specs = [
+        pl.BlockSpec((bq, 4), lambda i, t, k: (i, 0)),
+        pl.BlockSpec((4, bq if gathered else 1, blk),
+                     lambda i, t, k: (0, rows(i), t * nk + k)),
     ]
-    if alive is not None:
-        specs.append(pl.BlockSpec((bq, 1, cap), lambda fi, i: (i, fi, 0)))
-    return specs
-
-
-def gather_count_skip_pallas(q4: jax.Array, gtiles: jax.Array,
-                             gcboxes: jax.Array, bq: int = DEFAULT_BQ,
-                             interpret: bool = False, *,
-                             alive: jax.Array | None = None) -> jax.Array:
-    """Routed probe with chunk skipping, count form.
-
-    q4: (4, Q); gtiles: (Q, F, 4, cap); gcboxes: (Q, F, C, 4) each
-    query's gathered candidate chunk boxes (C == cap // CHUNK)
-    -> (Q, F) int32.  ``alive``: (Q, F, cap) gathered alive mask —
-    all-dead chunk blocks are skipped entirely.
-    """
-    q = q4.shape[1]
-    _, f, _, cap = gtiles.shape
-    grid = (f, q // bq)
-    c = gcboxes.shape[2]
-    args = ((q4, gtiles, gcboxes) if alive is None
-            else (q4, gtiles, gcboxes, alive))
-    return pl.pallas_call(
-        (_gather_count_skip_kernel if alive is None
-         else _gather_count_skip_alive_kernel),
-        grid=grid,
-        in_specs=_gather_skip_specs(bq, cap, c, alive),
-        out_specs=pl.BlockSpec((bq, 1), lambda fi, i: (i, fi)),
-        out_shape=jax.ShapeDtypeStruct((q, f), jnp.int32),
+    if skip:
+        # (R, n_cols, C, 4) -> (n_cols·nk, R, 4G): member block k of
+        # column t reads its G chunk boxes as one (rows, 4G) slab
+        cb = cboxes.reshape(r, n_cols * nk, 4 * g)
+        args.append(jnp.swapaxes(cb, 0, 1))
+        in_specs.append(pl.BlockSpec((1, bq if gathered else 1, 4 * g),
+                                     lambda i, t, k: (t * nk + k, rows(i), 0)))
+    if masked:
+        args.append(alive.reshape(r, n_cols * cap))
+        in_specs.append(pl.BlockSpec((bq if gathered else 1, blk),
+                                     lambda i, t, k: (rows(i), t * nk + k)))
+    if mask:
+        out_spec = pl.BlockSpec((bq, blk), lambda i, t, k: (i, t * nk + k))
+        out_shape = jax.ShapeDtypeStruct((nq, n_cols * cap), jnp.bool_)
+    else:
+        out_spec = pl.BlockSpec((bq, CHUNK), lambda i, t, k: (i, t))
+        out_shape = jax.ShapeDtypeStruct((nq, n_cols * CHUNK), jnp.int32)
+    out = pl.pallas_call(
+        functools.partial(_probe_kernel, g=g, skip=skip, masked=masked,
+                          mask=mask),
+        grid=(nq // bq, n_cols, nk),
+        in_specs=in_specs,
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(*args)
-
-
-def gather_mask_skip_pallas(q4: jax.Array, gtiles: jax.Array,
-                            gcboxes: jax.Array, bq: int = DEFAULT_BQ,
-                            interpret: bool = False, *,
-                            alive: jax.Array | None = None) -> jax.Array:
-    """Routed mask with chunk skipping: -> (Q, F, cap) bool (skipped
-    chunks and dead slots read False)."""
-    q = q4.shape[1]
-    _, f, _, cap = gtiles.shape
-    grid = (f, q // bq)
-    c = gcboxes.shape[2]
-    args = ((q4, gtiles, gcboxes) if alive is None
-            else (q4, gtiles, gcboxes, alive))
-    return pl.pallas_call(
-        (_gather_mask_skip_kernel if alive is None
-         else _gather_mask_skip_alive_kernel),
-        grid=grid,
-        in_specs=_gather_skip_specs(bq, cap, c, alive),
-        out_specs=pl.BlockSpec((bq, 1, cap), lambda fi, i: (i, fi, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, f, cap), jnp.bool_),
-        interpret=interpret,
-    )(*args)
+    if mask:
+        return out.reshape(nq, n_cols, cap)
+    return jnp.sum(out.reshape(nq, n_cols, CHUNK), axis=2)

@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the range_probe kernels.
 
 Handles padding to block multiples (with never-intersecting sentinel
-boxes), the component-major layouts the kernels want, and CPU fallback
-to interpret mode.  The natural caller is ``repro.serve.engine``, whose
+boxes) and the component-major layouts the kernel wants.  Off the TPU
+(the CPU test path) ``interpret=None`` selects interpret mode or the
+fused-jnp ``ref`` twin by backend; on the TPU every wrapper runs the
+compiled kernel.  The natural caller is ``repro.serve.engine``, whose
 staged layouts are already sentinel-padded and 128-aligned.
 
 Candidate-list contract (``gathered_*``): ``cand`` is (Q, F) int32 tile
@@ -34,64 +36,43 @@ import jax.numpy as jnp
 
 from ...core.geometry import SENTINEL_BOX
 from . import kernel
-from .kernel import CHUNK  # noqa: F401  (re-export: staging chunks on this)
+from .kernel import CHUNK  # re-exported: staging chunks on this
 
 _SENTINEL = jnp.array(SENTINEL_BOX, jnp.float32)
-_LANE = 128
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pad_queries_cm(qboxes: jax.Array, bq: int) -> jax.Array:
-    """(Q, 4) -> component-major (4, Q_pad) with sentinel padding."""
-    q = qboxes.shape[0]
-    pad = (-q) % bq
+def _pad_queries(qboxes: jax.Array, bq: int) -> jax.Array:
+    """(Q, 4) -> (Q_pad, 4) f32 with sentinel padding to a block multiple."""
+    qboxes = qboxes.astype(jnp.float32)
+    pad = (-qboxes.shape[0]) % bq
     if pad:
         qboxes = jnp.concatenate(
             [qboxes, jnp.broadcast_to(_SENTINEL, (pad, 4))], axis=0)
-    return qboxes.T
+    return qboxes
 
 
-def _pad_tiles_cm(tiles: jax.Array) -> jax.Array:
-    """(T, cap, 4) -> per-tile component-major (T, 4, cap_pad)."""
-    cap = tiles.shape[1]
-    pad = (-cap) % _LANE
+def _pad_cap(tiles: jax.Array) -> jax.Array:
+    """(T, cap, 4) -> (T, cap_pad, 4) f32 with sentinel member padding."""
+    tiles = tiles.astype(jnp.float32)
+    pad = (-tiles.shape[1]) % CHUNK
     if pad:
         tiles = jnp.concatenate(
             [tiles, jnp.broadcast_to(_SENTINEL, (tiles.shape[0], pad, 4))],
             axis=1)
-    return jnp.swapaxes(tiles, 1, 2)
+    return tiles
 
 
 def _pad_alive(alive: jax.Array) -> jax.Array:
     """(T, cap) bool -> (T, cap_pad) with False (dead) padding."""
     cap = alive.shape[1]
-    pad = (-cap) % _LANE
+    pad = (-cap) % CHUNK
     if pad:
         alive = jnp.pad(alive, ((0, 0), (0, pad)))
     return alive
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "interpret"))
-def probe_counts(qboxes: jax.Array, tiles: jax.Array,
-                 bq: int = kernel.DEFAULT_BQ,
-                 interpret: bool | None = None, *,
-                 alive: jax.Array | None = None) -> jax.Array:
-    """Per-(query, tile) hit counts.
-
-    qboxes: (Q, 4), tiles: (T, cap, 4) sentinel-padded member boxes
-    -> (Q, T) int32.  ``alive``: (T, cap) bool — dead slots never count.
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    q = qboxes.shape[0]
-    q4 = _pad_queries_cm(qboxes.astype(jnp.float32), bq)
-    t3 = _pad_tiles_cm(tiles.astype(jnp.float32))
-    a = None if alive is None else _pad_alive(alive)
-    counts = kernel.count_pallas(q4, t3, bq, interpret=interpret, alive=a)
-    return counts.T[:q]
 
 
 def _append_pad_row(table: jax.Array, pad_value) -> tuple[jax.Array, int]:
@@ -103,6 +84,82 @@ def _append_pad_row(table: jax.Array, pad_value) -> tuple[jax.Array, int]:
     row = jnp.broadcast_to(jnp.asarray(pad_value, table.dtype),
                            (1,) + table.shape[1:])
     return jnp.concatenate([table, row], axis=0), t
+
+
+def _use_ref(interpret: bool | None) -> bool:
+    """``interpret=None`` off the TPU runs the fused-jnp ``ref`` executor
+    (the gathered layouts' interpret-mode kernel is slow on CPU); an
+    explicit ``interpret`` always runs the Pallas kernel."""
+    return interpret is None and _interpret_default()
+
+
+def _dense(qboxes, tiles, cboxes, alive, bq, interpret, mask):
+    """All-tile probe through the kernel: -> (Q, T) counts or
+    (Q, T, cap) mask."""
+    q, cap = qboxes.shape[0], tiles.shape[1]
+    members = jnp.moveaxis(_pad_cap(tiles), 2, 0)[:, None]  # (4,1,T,cap_p)
+    out = kernel.probe_pallas(
+        _pad_queries(qboxes, bq), members,
+        None if cboxes is None else cboxes.astype(jnp.float32)[None],
+        alive=None if alive is None else _pad_alive(alive)[None],
+        mask=mask, bq=bq, interpret=interpret)
+    return out[:q, :, :cap] if mask else out[:q]
+
+
+def _gathered(qboxes, tiles, cboxes, cand, alive, bq, interpret, mask):
+    """Candidate-tile probe through the kernel: pad queries to a block
+    multiple, remap -1 candidates to an appended all-sentinel (all-dead)
+    tile, gather each query's candidate stack -> (Q, F) counts or
+    (Q, F, cap) mask."""
+    q, cap = qboxes.shape[0], tiles.shape[1]
+    tiles_p, t = _append_pad_row(_pad_cap(tiles), _SENTINEL)
+    cidx = jnp.where(cand >= 0, cand, t)
+    pad = (-q) % bq
+    if pad:
+        cidx = jnp.concatenate(
+            [cidx, jnp.full((pad, cand.shape[1]), t, cidx.dtype)], axis=0)
+    members = jnp.moveaxis(tiles_p, 2, 0)[:, cidx]   # (4, Q_pad, F, cap_p)
+    cb = None
+    if cboxes is not None:
+        cb = _append_pad_row(cboxes.astype(jnp.float32), _SENTINEL)[0][cidx]
+    a = None
+    if alive is not None:
+        a = _append_pad_row(_pad_alive(alive), False)[0][cidx]
+    out = kernel.probe_pallas(_pad_queries(qboxes, bq), members, cb,
+                              alive=a, mask=mask, bq=bq,
+                              interpret=interpret)
+    return out[:q, :, :cap] if mask else out[:q]
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "interpret"))
+def probe_counts(qboxes: jax.Array, tiles: jax.Array,
+                 bq: int = kernel.DEFAULT_BQ,
+                 interpret: bool | None = None, *,
+                 alive: jax.Array | None = None) -> jax.Array:
+    """Per-(query, tile) hit counts.
+
+    qboxes: (Q, 4), tiles: (T, cap, 4) sentinel-padded member boxes
+    -> (Q, T) int32.  ``alive``: (T, cap) bool — dead slots never count.
+    ``interpret=None`` runs the kernel in interpret mode off the TPU.
+    """
+    if interpret is None:
+        interpret = _interpret_default()
+    return _dense(qboxes, tiles, None, alive, bq, interpret, mask=False)
+
+
+@functools.partial(jax.jit, static_argnames=("bq", "interpret"))
+def probe_mask(qboxes: jax.Array, tiles: jax.Array,
+               bq: int = kernel.DEFAULT_BQ,
+               interpret: bool | None = None, *,
+               alive: jax.Array | None = None) -> jax.Array:
+    """Full hit table for id extraction.
+
+    qboxes: (Q, 4), tiles: (T, cap, 4) -> (Q, T, cap) bool (un-padded
+    view).  O(Q·T·cap) output — the count path is the throughput path.
+    """
+    if interpret is None:
+        interpret = _interpret_default()
+    return _dense(qboxes, tiles, None, alive, bq, interpret, mask=True)
 
 
 # reprolint: disable=kernel-twin-parity -- pure data mover: gathers raw
@@ -137,39 +194,6 @@ def gathered_alive(alive: jax.Array, cand: jax.Array) -> jax.Array:
     return alive_p[jnp.where(cand >= 0, cand, t)]
 
 
-def _gather_cm(qboxes: jax.Array, tiles: jax.Array, cand: jax.Array,
-               bq: int) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Shared gathered-probe staging: pad queries to a block multiple,
-    remap -1 candidates to an appended all-sentinel tile, and gather the
-    component-major candidate stack.
-
-    -> ``(q4[4, Q_pad], gtiles[Q_pad, F, 4, cap_pad], cidx[Q_pad, F])``
-    (``cidx`` is the padded, remapped candidate index — reused to
-    gather per-candidate chunk boxes for the ``*_skip`` kernels).
-    """
-    tiles_p, t = _append_pad_row(tiles.astype(jnp.float32), _SENTINEL)
-    t3 = _pad_tiles_cm(tiles_p)                    # (T+1, 4, cap_pad)
-    q = qboxes.shape[0]
-    pad = (-q) % bq
-    cidx = jnp.where(cand >= 0, cand, t)
-    if pad:
-        cidx = jnp.concatenate(
-            [cidx, jnp.full((pad, cand.shape[1]), t, cidx.dtype)], axis=0)
-    q4 = _pad_queries_cm(qboxes.astype(jnp.float32), bq)
-    return q4, t3[cidx], cidx
-
-
-def _gather_alive_cm(alive: jax.Array | None,
-                     cidx: jax.Array) -> jax.Array | None:
-    """Kernel-path companion of ``gathered_alive``: lane-pad with False,
-    append the all-dead pad row, gather by the already-remapped ``cidx``
-    -> (Q_pad, F, cap_pad) bool (or None passthrough)."""
-    if alive is None:
-        return None
-    alive_p, _ = _append_pad_row(_pad_alive(alive), False)
-    return alive_p[cidx]
-
-
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def gathered_counts(qboxes: jax.Array, tiles: jax.Array, cand: jax.Array,
                     bq: int = kernel.DEFAULT_BQ,
@@ -181,24 +205,18 @@ def gathered_counts(qboxes: jax.Array, tiles: jax.Array, cand: jax.Array,
     cand: (Q, F) int32 candidate tile indices (-1 = padding)
     -> (Q, F) int32.  O(Q·F·cap) work vs the dense O(Q·T·cap).
 
-    ``interpret=None`` picks the backend's best executor: the Pallas
-    kernel on TPU, the fused-jnp gather+compare off-TPU (the gathered
-    layout's blocked interpret-mode kernel is slow on CPU, unlike the
-    dense one).  Pass ``interpret=True`` to force the interpret-mode
-    kernel (validation path); results are identical either way.
+    ``interpret=None`` picks the backend's executor: the Pallas kernel
+    on TPU, the fused-jnp gather+compare off it.  Pass
+    ``interpret=True`` to force the interpret-mode kernel (validation
+    path); results are identical either way.
     """
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return ref.gathered_counts(
             qboxes.astype(jnp.float32), gathered_rows(tiles, cand),
             None if alive is None else gathered_alive(alive, cand))
-    if interpret is None:
-        interpret = False
-    q = qboxes.shape[0]
-    q4, gt, cidx = _gather_cm(qboxes, tiles, cand, bq)
-    ga = _gather_alive_cm(alive, cidx)
-    return kernel.gather_count_pallas(q4, gt, bq, interpret=interpret,
-                                      alive=ga)[:q]
+    return _gathered(qboxes, tiles, None, cand, alive, bq, bool(interpret),
+                     mask=False)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -213,39 +231,13 @@ def gathered_mask(qboxes: jax.Array, tiles: jax.Array, cand: jax.Array,
     query j intersects member c of its f-th candidate tile.  Executor
     selection as in ``gathered_counts``.
     """
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return ref.gathered_mask(
             qboxes.astype(jnp.float32), gathered_rows(tiles, cand),
             None if alive is None else gathered_alive(alive, cand))
-    if interpret is None:
-        interpret = False
-    q, cap = qboxes.shape[0], tiles.shape[1]
-    q4, gt, cidx = _gather_cm(qboxes, tiles, cand, bq)
-    ga = _gather_alive_cm(alive, cidx)
-    full = kernel.gather_mask_pallas(q4, gt, bq, interpret=interpret,
-                                     alive=ga)
-    return full[:q, :, :cap]
-
-
-@functools.partial(jax.jit, static_argnames=("bq", "interpret"))
-def probe_mask(qboxes: jax.Array, tiles: jax.Array,
-               bq: int = kernel.DEFAULT_BQ,
-               interpret: bool | None = None, *,
-               alive: jax.Array | None = None) -> jax.Array:
-    """Full hit table for id extraction.
-
-    qboxes: (Q, 4), tiles: (T, cap, 4) -> (Q, T, cap) bool (un-padded
-    view).  O(Q·T·cap) output — the count path is the throughput path.
-    """
-    if interpret is None:
-        interpret = _interpret_default()
-    q, cap = qboxes.shape[0], tiles.shape[1]
-    q4 = _pad_queries_cm(qboxes.astype(jnp.float32), bq)
-    t3 = _pad_tiles_cm(tiles.astype(jnp.float32))
-    a = None if alive is None else _pad_alive(alive)
-    full = kernel.mask_pallas(q4, t3, bq, interpret=interpret, alive=a)
-    return jnp.swapaxes(full, 0, 1)[:q, :, :cap]
+    return _gathered(qboxes, tiles, None, cand, alive, bq, bool(interpret),
+                     mask=True)
 
 
 # --------------------------------------------------------------------------
@@ -278,20 +270,13 @@ def probe_counts_skip(qboxes: jax.Array, tiles: jax.Array,
     ``gathered_counts``: the Pallas skip kernel on TPU (or
     ``interpret=True``), the fused chunk-masked jnp path off-TPU.
     """
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return ref.probe_counts_skip(qboxes.astype(jnp.float32),
                                      tiles.astype(jnp.float32),
                                      cboxes.astype(jnp.float32), alive)
-    if interpret is None:
-        interpret = False
-    q = qboxes.shape[0]
-    q4 = _pad_queries_cm(qboxes.astype(jnp.float32), bq)
-    t3 = _pad_tiles_cm(tiles.astype(jnp.float32))
-    a = None if alive is None else _pad_alive(alive)
-    counts = kernel.count_skip_pallas(q4, t3, cboxes.astype(jnp.float32),
-                                      bq, interpret=interpret, alive=a)
-    return counts.T[:q]
+    return _dense(qboxes, tiles, cboxes, alive, bq, bool(interpret),
+                  mask=False)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -303,21 +288,14 @@ def probe_mask_skip(qboxes: jax.Array, tiles: jax.Array,
     (un-padded view); same chunk-box contract (boxes must bound the
     probed ``tiles`` — staged boxes pair with ``canon_tiles``) and
     executor selection as ``probe_counts_skip``."""
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return jnp.swapaxes(
             ref.probe_mask_skip(qboxes.astype(jnp.float32),
                                 tiles.astype(jnp.float32),
                                 cboxes.astype(jnp.float32), alive), 0, 1)
-    if interpret is None:
-        interpret = False
-    q, cap = qboxes.shape[0], tiles.shape[1]
-    q4 = _pad_queries_cm(qboxes.astype(jnp.float32), bq)
-    t3 = _pad_tiles_cm(tiles.astype(jnp.float32))
-    a = None if alive is None else _pad_alive(alive)
-    full = kernel.mask_skip_pallas(q4, t3, cboxes.astype(jnp.float32),
-                                   bq, interpret=interpret, alive=a)
-    return jnp.swapaxes(full, 0, 1)[:q, :, :cap]
+    return _dense(qboxes, tiles, cboxes, alive, bq, bool(interpret),
+                  mask=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -333,21 +311,14 @@ def gathered_counts_skip(qboxes: jax.Array, tiles: jax.Array,
     ``gathered_counts`` whenever the chunk boxes bound their members —
     the serving hot path's local-index executor.
     """
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return ref.gathered_counts_skip(
             qboxes.astype(jnp.float32), gathered_rows(tiles, cand),
             gathered_chunk_boxes(cboxes, cand),
             None if alive is None else gathered_alive(alive, cand))
-    if interpret is None:
-        interpret = False
-    q = qboxes.shape[0]
-    q4, gt, cidx = _gather_cm(qboxes, tiles, cand, bq)
-    cb_p, _ = _append_pad_row(cboxes.astype(jnp.float32), _SENTINEL)
-    ga = _gather_alive_cm(alive, cidx)
-    out = kernel.gather_count_skip_pallas(q4, gt, cb_p[cidx], bq,
-                                          interpret=interpret, alive=ga)
-    return out[:q]
+    return _gathered(qboxes, tiles, cboxes, cand, alive, bq,
+                     bool(interpret), mask=False)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
@@ -358,21 +329,14 @@ def gathered_mask_skip(qboxes: jax.Array, tiles: jax.Array,
                        alive: jax.Array | None = None) -> jax.Array:
     """Routed hit table with chunk skipping: -> (Q, F, cap) bool
     (un-padded view); executor selection as in ``gathered_counts_skip``."""
-    if interpret is None and _interpret_default():
+    if _use_ref(interpret):
         from . import ref
         return ref.gathered_mask_skip(
             qboxes.astype(jnp.float32), gathered_rows(tiles, cand),
             gathered_chunk_boxes(cboxes, cand),
             None if alive is None else gathered_alive(alive, cand))
-    if interpret is None:
-        interpret = False
-    q, cap = qboxes.shape[0], tiles.shape[1]
-    q4, gt, cidx = _gather_cm(qboxes, tiles, cand, bq)
-    cb_p, _ = _append_pad_row(cboxes.astype(jnp.float32), _SENTINEL)
-    ga = _gather_alive_cm(alive, cidx)
-    full = kernel.gather_mask_skip_pallas(q4, gt, cb_p[cidx], bq,
-                                          interpret=interpret, alive=ga)
-    return full[:q, :, :cap]
+    return _gathered(qboxes, tiles, cboxes, cand, alive, bq,
+                     bool(interpret), mask=True)
 
 
 @jax.jit
