@@ -176,6 +176,7 @@ def probe_pallas(qboxes: jax.Array, tiles: jax.Array,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="range_probe",
     )(*args)
     if mask:
         return out.reshape(nq, n_cols, cap)

@@ -47,6 +47,11 @@ reduces the partials: ``merge_owner_counts`` (plain
 integer sum — canonical copies make hits owner-disjoint) and
 ``merge_owner_ids`` (duplicate-free union by one ascending sort).
 Merged answers are bit-identical to the single-device dense sweep.
+
+Device operations carry two ``jax.named_scope`` names that do not move
+with the shapes: ``probe`` (the gathered probe kernel's call) and
+``idcompact`` (the id compaction of every id path: keyed id table,
+sort, slice).
 """
 from __future__ import annotations
 
@@ -108,11 +113,13 @@ def range_ids(qboxes: jax.Array, canon_tiles: jax.Array, ids: jax.Array,
     q = qboxes.shape[0]
     mask = rops.probe_mask(qboxes, canon_tiles, alive=alive)  # (Q, T, cap)
     flat = mask.reshape(q, -1) & (ids.reshape(-1) >= 0)[None, :]
-    keyed = jnp.where(flat, ids.reshape(-1)[None, :], _BIG_ID)
-    if keyed.shape[1] < max_hits:          # small layout, wide id budget
-        keyed = jnp.pad(keyed, ((0, 0), (0, max_hits - keyed.shape[1])),
-                        constant_values=_BIG_ID)
-    top = jax.lax.sort(keyed, dimension=1)[:, :max_hits]
+    with jax.named_scope("idcompact"):
+        keyed = jnp.where(flat, ids.reshape(-1)[None, :], _BIG_ID)
+        if keyed.shape[1] < max_hits:      # small layout, wide id budget
+            keyed = jnp.pad(keyed,
+                            ((0, 0), (0, max_hits - keyed.shape[1])),
+                            constant_values=_BIG_ID)
+        top = jax.lax.sort(keyed, dimension=1)[:, :max_hits]
     hit_ids = jnp.where(top < _BIG_ID, top, -1)
     counts = jnp.sum(flat, axis=1, dtype=jnp.int32)
     return hit_ids, counts, counts > max_hits
@@ -143,12 +150,15 @@ def pruned_range_counts(qboxes: jax.Array, canon_tiles: jax.Array,
     chunks' canonical members (a staging invariant), so a skipped
     chunk provably holds no hit.  ``alive``: (T, cap) tombstone mask.
     """
-    if chunk_boxes is None:
-        return jnp.sum(rops.gathered_counts(qboxes, canon_tiles, cand,
-                                            alive=alive), axis=1)
-    return jnp.sum(rops.gathered_counts_skip(qboxes, canon_tiles,
-                                             chunk_boxes, cand,
-                                             alive=alive), axis=1)
+    with jax.named_scope("probe"):
+        if chunk_boxes is None:
+            per_tile = rops.gathered_counts(qboxes, canon_tiles, cand,
+                                            alive=alive)
+        else:
+            per_tile = rops.gathered_counts_skip(qboxes, canon_tiles,
+                                                 chunk_boxes, cand,
+                                                 alive=alive)
+    return jnp.sum(per_tile, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("max_hits",))
@@ -171,19 +181,22 @@ def pruned_range_ids(qboxes: jax.Array, canon_tiles: jax.Array,
     appear twice in the gathered hit table.
     """
     q = qboxes.shape[0]
-    if chunk_boxes is None:
-        mask = rops.gathered_mask(qboxes, canon_tiles, cand,
-                                  alive=alive)                # (Q, F, cap)
-    else:
-        mask = rops.gathered_mask_skip(qboxes, canon_tiles, chunk_boxes,
-                                       cand, alive=alive)
+    with jax.named_scope("probe"):
+        if chunk_boxes is None:
+            mask = rops.gathered_mask(qboxes, canon_tiles, cand,
+                                      alive=alive)            # (Q, F, cap)
+        else:
+            mask = rops.gathered_mask_skip(qboxes, canon_tiles,
+                                           chunk_boxes, cand, alive=alive)
     gids = rops.gathered_ids(ids, cand)                    # (Q, F, cap)
     flat = mask.reshape(q, -1) & (gids.reshape(q, -1) >= 0)
-    keyed = jnp.where(flat, gids.reshape(q, -1), _BIG_ID)
-    if keyed.shape[1] < max_hits:          # narrow gather, wide id budget
-        keyed = jnp.pad(keyed, ((0, 0), (0, max_hits - keyed.shape[1])),
-                        constant_values=_BIG_ID)
-    top = jax.lax.sort(keyed, dimension=1)[:, :max_hits]
+    with jax.named_scope("idcompact"):
+        keyed = jnp.where(flat, gids.reshape(q, -1), _BIG_ID)
+        if keyed.shape[1] < max_hits:      # narrow gather, wide id budget
+            keyed = jnp.pad(keyed,
+                            ((0, 0), (0, max_hits - keyed.shape[1])),
+                            constant_values=_BIG_ID)
+        top = jax.lax.sort(keyed, dimension=1)[:, :max_hits]
     hit_ids = jnp.where(top < _BIG_ID, top, -1)
     counts = jnp.sum(flat, axis=1, dtype=jnp.int32)
     return hit_ids, counts, counts > max_hits
@@ -234,13 +247,15 @@ def merge_owner_ids(pids: jax.Array, pcounts: jax.Array, slots: jax.Array,
     live = slots >= 0
     idx = jnp.where(live, slots, qpd)
     col = jnp.arange(d)[:, None]
-    keyed = jnp.where(live[..., None] & (pids >= 0), pids, _BIG_ID)
-    tbl = jnp.full((qpd + 1, d, mh), _BIG_ID, jnp.int32).at[idx, col].set(keyed)
-    flat = tbl[:qpd].reshape(qpd, d * mh)
-    if flat.shape[1] < max_hits:
-        flat = jnp.pad(flat, ((0, 0), (0, max_hits - flat.shape[1])),
-                       constant_values=_BIG_ID)
-    top = jax.lax.sort(flat, dimension=1)[:, :max_hits]
+    with jax.named_scope("idcompact"):
+        keyed = jnp.where(live[..., None] & (pids >= 0), pids, _BIG_ID)
+        tbl = jnp.full((qpd + 1, d, mh), _BIG_ID,
+                       jnp.int32).at[idx, col].set(keyed)
+        flat = tbl[:qpd].reshape(qpd, d * mh)
+        if flat.shape[1] < max_hits:
+            flat = jnp.pad(flat, ((0, 0), (0, max_hits - flat.shape[1])),
+                           constant_values=_BIG_ID)
+        top = jax.lax.sort(flat, dimension=1)[:, :max_hits]
     hit_ids = jnp.where(top < _BIG_ID, top, -1)
     counts = merge_owner_counts(pcounts, slots, qpd)
     return hit_ids, counts, counts > max_hits

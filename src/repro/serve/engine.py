@@ -51,6 +51,14 @@ frontier is every tile — the dense sweep).  Converged candidate widths
 are remembered per query kind (``WidthPolicy``), so steady query
 streams pay recompiles and kNN widening ladders once.
 
+Host spans (``jax.profiler.TraceAnnotation``, recorded only while a
+profiler trace is active) mark each batch's host phases: ``serve.route``
+(routing: overlap, host fold, width ratchet, candidates; for kNN the
+converged frontier), its child ``serve.heat`` (folding the batch into
+the heat tracker), ``serve.fanout_stats`` (the reported fan-out metric)
+and ``serve.probe`` (dispatching the executor; the device runs on past
+it; ``attempt`` numbers a kNN batch's frontier widenings).
+
 Single-process use passes ``mesh=None`` and gets the same jitted maths
 without the collective plumbing (sharded placement then runs the
 exchange in vmap simulation — same answers, one device).
@@ -67,6 +75,7 @@ import logging
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from ..core.partition import api
@@ -349,11 +358,12 @@ class SpatialServer:
     def _observe(self, cand) -> None:
         """Fold one routed batch into the heat tracker; auto-rebalance
         every ``config.policy.rebalance_every`` observed batches."""
-        self.heat.observe(np.asarray(cand))
-        self._batches_since_rebalance += 1
-        every = self.config.policy.rebalance_every
-        if every is not None and self._batches_since_rebalance >= every:
-            self.rebalance()
+        with TraceAnnotation("serve.heat"):
+            self.heat.observe(np.asarray(cand))
+            self._batches_since_rebalance += 1
+            every = self.config.policy.rebalance_every
+            if every is not None and self._batches_since_rebalance >= every:
+                self.rebalance()
 
     # -- routing helpers (host side, per batch) ---------------------------
 
@@ -366,22 +376,24 @@ class SpatialServer:
         ratcheted through the width cache so narrower follow-up batches
         reuse the compiled step.  Returns ``(cand[Q, F], costs[Q], F)``.
         """
-        hit = router.probe_overlap(self.probe_boxes, qboxes)
-        # reprolint: disable=host-sync -- routing is host-side by design:
-        # one fold of the overlap matrix feeds the width ratchet + packing
-        pf = np.asarray(jnp.sum(hit, axis=1, dtype=jnp.int32))
-        floor = _f_width(int(pf.max(initial=0)), self.stats["t_live"])
-        f = self.widths.at_least("range", floor)
-        cand, _, _ = router.candidates_from_overlap(hit, f)
-        self.widths.observe("range", f)
-        self._observe(cand)
+        with TraceAnnotation("serve.route"):
+            hit = router.probe_overlap(self.probe_boxes, qboxes)
+            # reprolint: disable=host-sync -- routing is host-side by design:
+            # one fold of the overlap matrix feeds the width ratchet + packing
+            pf = np.asarray(jnp.sum(hit, axis=1, dtype=jnp.int32))
+            floor = _f_width(int(pf.max(initial=0)), self.stats["t_live"])
+            f = self.widths.at_least("range", floor)
+            cand, _, _ = router.candidates_from_overlap(hit, f)
+            self.widths.observe("range", f)
+            self._observe(cand)
         return cand, pf.astype(np.float64), f
 
     def _fanout_stats(self, qboxes: jax.Array) -> dict:
         """The paper's reported metric: region fan-out from the global
         index (independent of the executor's probe-box routing)."""
-        _, fanout = router.route_range(self.parts, qboxes)
-        fanout_np = np.asarray(fanout)
+        with TraceAnnotation("serve.fanout_stats"):
+            _, fanout = router.route_range(self.parts, qboxes)
+            fanout_np = np.asarray(fanout)
         return dict(fanout_mean=float(fanout_np.mean()),
                     fanout_max=int(fanout_np.max()))
 
@@ -396,10 +408,12 @@ class SpatialServer:
         stats = self._fanout_stats(qboxes)
         if self._use_pruned(pruned):
             cand, costs, f = self._route_batch(qboxes)
-            counts, xstats = self.tiles.range_counts(qboxes, cand, costs)
+            with TraceAnnotation("serve.probe"):
+                counts, xstats = self.tiles.range_counts(qboxes, cand, costs)
             stats.update(mode=self.tiles.mode, f_max=f, **xstats)
         else:
-            counts, xstats = self.tiles.dense_range_counts(qboxes)
+            with TraceAnnotation("serve.probe"):
+                counts, xstats = self.tiles.dense_range_counts(qboxes)
             stats.update(mode="dense", **xstats)
         return counts, stats
 
@@ -410,12 +424,14 @@ class SpatialServer:
         stats = self._fanout_stats(qboxes)
         if self._use_pruned(pruned):
             cand, costs, f = self._route_batch(qboxes)
-            hit_ids, counts, overflow, xstats = self.tiles.range_ids(
-                qboxes, cand, costs, max_hits)
+            with TraceAnnotation("serve.probe"):
+                hit_ids, counts, overflow, xstats = self.tiles.range_ids(
+                    qboxes, cand, costs, max_hits)
             stats.update(mode=self.tiles.mode, f_max=f, **xstats)
         else:
-            hit_ids, counts, overflow, xstats = self.tiles.dense_range_ids(
-                qboxes, max_hits)
+            with TraceAnnotation("serve.probe"):
+                hit_ids, counts, overflow, xstats = \
+                    self.tiles.dense_range_ids(qboxes, max_hits)
             stats.update(mode="dense", **xstats)
         return hit_ids, counts, overflow, stats
 
@@ -436,13 +452,15 @@ class SpatialServer:
                 pts, k, max_cand)
             mode_stats = dict(mode=self.tiles.mode, **mode_stats)
         else:
-            nn_ids, nn_d2, overflow, xstats = self.tiles.dense_knn(
-                pts, k, max_cand)
+            with TraceAnnotation("serve.probe"):
+                nn_ids, nn_d2, overflow, xstats = self.tiles.dense_knn(
+                    pts, k, max_cand)
             mode_stats = dict(mode="dense", **xstats)
-        fanout = knn_mod.knn_fanout(jnp.asarray(pts),
-                                    jnp.asarray(nn_d2[:, -1]),
-                                    self.parts.boxes, self.parts.valid)
-        fanout_np = np.asarray(fanout)
+        with TraceAnnotation("serve.fanout_stats"):
+            fanout = knn_mod.knn_fanout(jnp.asarray(pts),
+                                        jnp.asarray(nn_d2[:, -1]),
+                                        self.parts.boxes, self.parts.valid)
+            fanout_np = np.asarray(fanout)
         stats = dict(fanout_mean=float(fanout_np.mean()),
                      fanout_max=int(fanout_np.max()), **mode_stats)
         return nn_ids, nn_d2, overflow, stats
@@ -465,8 +483,9 @@ class SpatialServer:
             wkey, _f_width(4 * k * t_live // max(n, 1) + 3, t_live))
         retries = 0
         while True:
-            nn_ids, nn_d2, radius, overflow, excl, xstats = \
-                self.tiles.knn_attempt(pts, k, max_cand, f)
+            with TraceAnnotation("serve.probe", attempt=retries):
+                nn_ids, nn_d2, radius, overflow, excl, xstats = \
+                    self.tiles.knn_attempt(pts, k, max_cand, f)
             miss = np.asarray(excl) <= np.asarray(radius) * np.sqrt(2.0)
             if not miss.any() or f >= t_live:
                 break
@@ -479,8 +498,9 @@ class SpatialServer:
         self.widths.observe(wkey, f)
         # heat sees the *converged* frontier — the tiles this batch
         # actually probed at its final width
-        cand, _, _ = router.candidate_knn(self.probe_boxes, pts, f)
-        self._observe(cand)
+        with TraceAnnotation("serve.route"):
+            cand, _, _ = router.candidate_knn(self.probe_boxes, pts, f)
+            self._observe(cand)
         overflow = np.asarray(overflow) | miss
         return (jnp.asarray(nn_ids), jnp.asarray(nn_d2),
                 jnp.asarray(overflow),

@@ -19,11 +19,18 @@ ascending sets, kNN is exact with the (distance, id) tie-break — so a
 padded batched response is **bit-identical** to calling the batched
 API directly with the same queries, which the frontend tests assert
 per placement.
+
+Spans (``jax.profiler.TraceAnnotation``, recorded only while a profiler
+trace is active): ``serve.execute`` covers the whole call and carries
+the batch's ``batch`` number, ``kind`` and ``width``; ``serve.fetch``
+covers the copy of the results to the host, where the worker waits for
+the device.  The server's own spans nest inside ``serve.execute``.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ...core import geometry
 from .plane import Batch
@@ -49,6 +56,12 @@ def execute_batch(server, batch: Batch) -> list:
     bool).  Everything is host numpy — responses never hold live
     device buffers.
     """
+    with TraceAnnotation("serve.execute", batch=batch.seq, kind=batch.kind,
+                         width=batch.width):
+        return _execute(server, batch)
+
+
+def _execute(server, batch: Batch) -> list:
     n = len(batch.requests)
     if batch.kind == "knn":
         k, max_cand = batch.params
@@ -57,21 +70,24 @@ def execute_batch(server, batch: Batch) -> list:
         pts = _padded(batch, centre)
         nn_ids, nn_d2, overflow, _ = server.knn(
             jnp.asarray(pts), k, max_cand=max_cand)
-        nn_ids, nn_d2 = np.asarray(nn_ids), np.asarray(nn_d2)
-        overflow = np.asarray(overflow)
+        with TraceAnnotation("serve.fetch"):
+            nn_ids, nn_d2 = np.asarray(nn_ids), np.asarray(nn_d2)
+            overflow = np.asarray(overflow)
         return [(nn_ids[i], nn_d2[i], bool(overflow[i])) for i in range(n)]
 
     qboxes = jnp.asarray(_padded(batch, _SENTINEL))
     if batch.kind == "range_counts":
         counts, _ = server.range_counts(qboxes)
-        counts = np.asarray(counts)
+        with TraceAnnotation("serve.fetch"):
+            counts = np.asarray(counts)
         return [int(counts[i]) for i in range(n)]
     if batch.kind == "range_ids":
         (max_hits,) = batch.params
         hit_ids, counts, overflow, _ = server.range_ids(
             qboxes, max_hits=max_hits)
-        hit_ids, counts = np.asarray(hit_ids), np.asarray(counts)
-        overflow = np.asarray(overflow)
+        with TraceAnnotation("serve.fetch"):
+            hit_ids, counts = np.asarray(hit_ids), np.asarray(counts)
+            overflow = np.asarray(overflow)
         return [(hit_ids[i], int(counts[i]), bool(overflow[i]))
                 for i in range(n)]
     raise ValueError(f"unknown batch kind {batch.kind!r}")

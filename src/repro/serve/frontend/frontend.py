@@ -15,6 +15,15 @@ The wrapper adds *only* IO: futures, a wake event, the worker thread,
 and wall-clock ``now``.  All policy (admission, fairness, deadlines,
 batch shapes) lives in ``RequestPlane`` and is covered by the
 virtual-clock tests.
+
+The dispatcher's spans (``jax.profiler.TraceAnnotation``, recorded only
+while a profiler trace is active) split its thread's time per batch:
+``serve.wait`` (asleep until a wake or a batch deadline),
+``serve.form`` (forming a batch; carries its ``batch`` number, ``kind``,
+``width`` and ``fill``) and ``serve.respond`` (answering its requests;
+``batch``).  The worker's ``serve.execute`` carries the same ``batch``
+number.  ``metrics.handoff_s`` takes one sample per batch: from its
+forming to the worker starting it, both on the frontend's clock.
 """
 from __future__ import annotations
 
@@ -22,6 +31,7 @@ import asyncio
 import concurrent.futures
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .clock import MonotonicClock
 from .config import FrontendConfig
@@ -152,37 +162,54 @@ class ServeFrontend:
                 # re-check under the cleared event: a submit between
                 # next_due() and clear() would otherwise be missed
                 if self.plane.next_due(self.clock.now()) is None:
-                    await self._wake.wait()
+                    with TraceAnnotation("serve.wait"):
+                        await self._wake.wait()
                 continue
             if due > now:
                 try:
-                    await asyncio.wait_for(self._wake.wait(), due - now)
+                    with TraceAnnotation("serve.wait"):
+                        await asyncio.wait_for(self._wake.wait(), due - now)
                     self._wake.clear()
                 except asyncio.TimeoutError:
                     pass
                 continue
-            batch, expired = self.plane.form_batch(now, force=self._closing)
-            self._finish_expired(expired, self.clock.now())
+            with TraceAnnotation("serve.form") as span:
+                batch, expired = self.plane.form_batch(now,
+                                                       force=self._closing)
+                self._finish_expired(expired, self.clock.now())
+                if batch is not None:
+                    span.set_metadata(batch=batch.seq, kind=batch.kind,
+                                      width=batch.width,
+                                      fill=len(batch.requests))
             if batch is None:
                 continue
             try:
-                results = await loop.run_in_executor(
-                    self._pool, execute_batch, self.server, batch)
+                started, results = await loop.run_in_executor(
+                    self._pool, self._execute, batch)
             except Exception as e:  # surface executor faults to callers
                 for req in batch.requests:
                     if req.future is not None and not req.future.done():
                         req.future.set_exception(e)
                 continue
-            done = self.clock.now()
-            for req, val in zip(batch.requests, results):
-                queue_s = batch.formed_at - req.arrival
-                execute_s = done - batch.formed_at
-                self.metrics.on_complete(req.tenant, queue_s, execute_s,
-                                         done - req.arrival)
-                if req.future is not None and not req.future.done():
-                    req.future.set_result(Response(
-                        Outcome.OK, value=val, queue_s=queue_s,
-                        execute_s=execute_s, total_s=done - req.arrival))
+            # recorded here, not on the worker: the histogram is not
+            # thread-safe
+            self.metrics.handoff_s.record(started - batch.formed_at)
+            with TraceAnnotation("serve.respond", batch=batch.seq):
+                done = self.clock.now()
+                for req, val in zip(batch.requests, results):
+                    queue_s = batch.formed_at - req.arrival
+                    execute_s = done - batch.formed_at
+                    self.metrics.on_complete(req.tenant, queue_s, execute_s,
+                                             done - req.arrival)
+                    if req.future is not None and not req.future.done():
+                        req.future.set_result(Response(
+                            Outcome.OK, value=val, queue_s=queue_s,
+                            execute_s=execute_s, total_s=done - req.arrival))
+
+    def _execute(self, batch) -> tuple:
+        """The worker's side of one batch -> (its start on the
+        frontend's clock, its results)."""
+        return self.clock.now(), execute_batch(self.server, batch)
 
     def _finish_expired(self, expired, now: float) -> None:
         for req in expired:

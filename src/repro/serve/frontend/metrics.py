@@ -101,6 +101,9 @@ class FrontendMetrics:
         self.queue_s = Histogram()      # arrival -> batch formed
         self.execute_s = Histogram()    # batch formed -> results ready
         self.total_s = Histogram()      # arrival -> response
+        # batch formed -> the worker starts it (ServeFrontend: the wait
+        # for the worker thread and the interpreter lock), one per batch
+        self.handoff_s = Histogram()
 
     def _tenant(self, tenant: str) -> _TenantCounters:
         tc = self.tenants.get(tenant)
@@ -167,6 +170,7 @@ class FrontendMetrics:
             queue_s=self.queue_s.snapshot(),
             execute_s=self.execute_s.snapshot(),
             total_s=self.total_s.snapshot(),
+            handoff_s=self.handoff_s.snapshot(),
             tenants={t: dataclasses.asdict(c)
                      for t, c in sorted(self.tenants.items())},
         )

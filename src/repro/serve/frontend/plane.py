@@ -84,6 +84,7 @@ class Batch:
     requests: list
     width: int
     formed_at: float
+    seq: int = -1             # the plane's batch number, in forming order
 
 
 @dataclasses.dataclass
@@ -166,6 +167,7 @@ class RequestPlane:
         self.metrics = metrics or FrontendMetrics()
         self._classes: dict[tuple, _ClassQueue] = {}
         self._seq = itertools.count()
+        self._batch_seq = itertools.count()
 
     # -- introspection ----------------------------------------------------
 
@@ -249,7 +251,7 @@ class RequestPlane:
                     self.metrics.on_timeout(r.tenant)
                 batch = Batch(kind=key[0], params=key[1], requests=take,
                               width=self.config.width_for(len(take)),
-                              formed_at=now)
+                              formed_at=now, seq=next(self._batch_seq))
                 self.metrics.on_batch(batch.width, len(take), self.pending)
                 return batch, expired
             # every popped request of this class had expired: move on

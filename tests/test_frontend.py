@@ -440,6 +440,30 @@ def test_asyncio_frontend_rejects_when_full(server, qboxes):
     asyncio.run(main())
 
 
+def test_asyncio_handoff_one_sample_per_batch():
+    """``handoff_s`` (batch formed -> the worker starts it) takes one
+    sample per batch, read on the frontend's clock: on a virtual clock
+    that never moves, each sample is exactly 0."""
+    class Counts:
+        def range_counts(self, qboxes):
+            return jnp.zeros(qboxes.shape[0], jnp.int32), {}
+
+    async def main():
+        fe = ServeFrontend(Counts(), FrontendConfig(ladder=(4,),
+                                                    max_delay=0.0))
+        fe.clock = VirtualClock(7.0)
+        async with fe:
+            rs = await asyncio.gather(
+                *[fe.range_counts(np.zeros(4)) for _ in range(10)])
+        assert all(r.ok for r in rs)
+        return fe.metrics
+
+    m = asyncio.run(main())
+    assert m.batches >= 3 and m.handoff_s.count == m.batches
+    assert m.handoff_s.samples == [0.0] * m.batches
+    assert m.snapshot()["handoff_s"]["count"] == m.batches
+
+
 def test_asyncio_close_drains_pending(server, qboxes):
     async def main():
         fe = ServeFrontend(server, FrontendConfig(
